@@ -28,16 +28,20 @@ is decided by a breadth-first frontier over
 
 ``analysis(model, history)`` mirrors the shape of
 ``knossos.competition/analysis`` results consumed at checker.clj:104-107:
-``{"valid?": bool, "op": ..., "configs": [...], "final-paths": [...]}``.
+``{"valid?": bool, "op": ..., "configs": [...], "final-paths": [...]}``,
+plus the request's record: ``spans`` and ``counts``
+(:func:`jepsen_tpu.obs.trace.request`, doc/observability.md).
 """
 
 from __future__ import annotations
 
+import contextvars
 import threading
 from typing import Any
 
 from jepsen_tpu.lin import prepare as _prepare_mod
 from jepsen_tpu.lin.prepare import PackedHistory, UnsupportedHistory
+from jepsen_tpu.obs import trace as _trace
 
 
 def analysis(model, history, algorithm: str = "competition", **kw) -> dict:
@@ -46,11 +50,20 @@ def analysis(model, history, algorithm: str = "competition", **kw) -> dict:
     algorithm: ``"tpu"`` (device BFS), ``"cpu"`` (host reference), or
     ``"competition"`` — race both like knossos.competition (the reference
     selects among these at checker.clj:90-93).
+
+    The result carries the request's span summary and counters under
+    ``spans`` and ``counts``.
     """
     known = {"witness", "cancel", "chunk", "cap_schedule", "explain",
              "checkpoint", "resume"}
     if kw.keys() - known:
         raise TypeError(f"unknown analysis options {kw.keys() - known}")
+    with _trace.request() as req:
+        r = _decide(model, history, algorithm, **kw)
+    return {**r, **req.record()}
+
+
+def _decide(model, history, algorithm: str, **kw) -> dict:
     try:
         packed = _prepare_mod.prepare(model, history)
     except UnsupportedHistory as e:
@@ -90,7 +103,6 @@ def device_check_packed(packed: PackedHistory, cancel=None, **kw) -> dict:
     fit its bounds — including every crashed-op history within them — else
     the sparse sort-dedup frontier (:mod:`jepsen_tpu.lin.bfs`)."""
     from jepsen_tpu.lin import bfs, dense
-    from jepsen_tpu.obs import trace as _trace
 
     known = {"chunk", "cap_schedule", "explain", "checkpoint", "resume",
              "frontier", "frontier_row", "partial", "host_caps"}
@@ -157,10 +169,12 @@ def _competition(packed: PackedHistory, cancel=None, **kw) -> dict:
                 if state["finished"] == 2:
                     done.set()
 
-    threads = [threading.Thread(target=run,
-                                args=(cpu.check_packed, "cpu", cpu_kw)),
-               threading.Thread(target=run,
-                                args=(device_check_packed, "tpu", dev_kw))]
+    # Each racer runs in a copy of this context, so its spans reach the
+    # open request.
+    threads = [threading.Thread(target=contextvars.copy_context().run,
+                                args=(run, fn, name, fkw))
+               for fn, name, fkw in ((cpu.check_packed, "cpu", cpu_kw),
+                                     (device_check_packed, "tpu", dev_kw))]
     for t in threads:
         t.start()
     done.wait()

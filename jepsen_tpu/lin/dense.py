@@ -44,7 +44,6 @@ vs. the reference's JVM graph search with a 32 GB heap
 from __future__ import annotations
 
 from functools import partial
-from time import monotonic as _monotonic
 
 import jax
 import jax.numpy as jnp
@@ -216,74 +215,82 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
     interpreted off-TPU), "xla" the lax.while_loop formulation, "auto"
     pallas on TPU-class hardware when the window fits, xla otherwise.
     """
-    pl = plan(p)
-    if pl is None:
-        return {"valid?": "unknown", "analyzer": "tpu-dense",
-                "error": "history outside dense engine bounds"}
-    w, ns, nil_id, init_id = pl
-    # Explicit callers get every chunk-entry snapshot; internal explain
-    # only ever replays from the LAST one (the dead row is always inside
-    # the current chunk), so retain just that and keep HBM flat.
-    keep_all = snapshots is not None
-    if explain and snapshots is None:
-        snapshots = []
+    # dense.prep: host work while the device idles (set-up here, then
+    # each chunk's tables and enqueue); dense.wait: the host blocked on
+    # the device (doc/observability.md).
+    with obs_trace.span("dense.prep"):
+        pl = plan(p)
+        if pl is None:
+            return {"valid?": "unknown", "analyzer": "tpu-dense",
+                    "error": "history outside dense engine bounds"}
+        w, ns, nil_id, init_id = pl
+        # Explicit callers get every chunk-entry snapshot; internal
+        # explain only ever replays from the LAST one (the dead row is
+        # always inside the current chunk), so retain just that and
+        # keep HBM flat.
+        keep_all = snapshots is not None
+        if explain and snapshots is None:
+            snapshots = []
 
-    if backend not in ("auto", "xla", "pallas"):
-        raise ValueError(f"unknown dense backend {backend!r}")
-    use_pallas = False
-    interpret = False
-    dp = None
-    if backend in ("auto", "pallas"):
-        from jepsen_tpu.lin import dense_pallas as dp
+        if backend not in ("auto", "xla", "pallas"):
+            raise ValueError(f"unknown dense backend {backend!r}")
+        use_pallas = False
+        interpret = False
+        dp = None
+        if backend in ("auto", "pallas"):
+            from jepsen_tpu.lin import dense_pallas as dp
 
-        fits = dp.supported_w(w) is not None
-        on_tpu = jax.devices()[0].platform == "tpu"
-        interpret = not on_tpu
-        if backend == "pallas":
-            if not fits:
-                raise ValueError(
-                    f"window {w} exceeds the pallas kernel bound "
-                    f"{dp.MAX_PALLAS_W}; use backend='xla'")
-            use_pallas = True
-        else:
-            use_pallas = fits and on_tpu
-    if p.R == 0:
-        return {"valid?": True, "analyzer": "tpu-dense", "configs": []}
+            fits = dp.supported_w(w) is not None
+            on_tpu = jax.devices()[0].platform == "tpu"
+            interpret = not on_tpu
+            if backend == "pallas":
+                if not fits:
+                    raise ValueError(
+                        f"window {w} exceeds the pallas kernel bound "
+                        f"{dp.MAX_PALLAS_W}; use backend='xla'")
+                use_pallas = True
+            else:
+                use_pallas = fits and on_tpu
+        if p.R == 0:
+            return {"valid?": True, "analyzer": "tpu-dense", "configs": []}
 
-    from jepsen_tpu.lin.bfs import _chunk_slice
+        from jepsen_tpu.lin.bfs import _chunk_slice
 
-    step_fn = p.kernel.step
-    ret_slot_h = np.asarray(p.ret_slot)
-    active_h = np.asarray(p.active)
-    slot_f_h = np.asarray(p.slot_f)
-    slot_v_h = np.asarray(p.slot_v)
-    W = p.window
+        step_fn = p.kernel.step
+        ret_slot_h = np.asarray(p.ret_slot)
+        active_h = np.asarray(p.active)
+        slot_f_h = np.asarray(p.slot_f)
+        slot_v_h = np.asarray(p.slot_v)
+        W = p.window
 
-    # Slot indices grow monotonically (freed slots are reused low-first,
-    # crashed slots accumulate upward), so early chunks run on an
-    # exponentially smaller bitmap: per-chunk width = that chunk's highest
-    # active slot, bucketed. Growing between chunks is a zero-pad of F.
-    row_hi = np.where(active_h.any(axis=1),
-                      W - np.argmax(active_h[:, ::-1], axis=1), 1)
+        # Slot indices grow monotonically (freed slots are reused
+        # low-first, crashed slots accumulate upward), so early chunks
+        # run on an exponentially smaller bitmap: per-chunk width = that
+        # chunk's highest active slot, bucketed. Growing between chunks
+        # is a zero-pad of F.
+        row_hi = np.where(active_h.any(axis=1),
+                          W - np.argmax(active_h[:, ::-1], axis=1), 1)
 
-    def bucket_w(need):
-        return next(b for b in _W_BUCKETS if b >= need)
+        def bucket_w(need):
+            return next(b for b in _W_BUCKETS if b >= need)
 
-    def pad_w(a, wc):
-        if a.shape[1] > wc:      # slots above wc are inactive in this chunk
-            return a[:, :wc]
-        if a.shape[1] == wc:
-            return a
-        pad = [(0, 0)] * a.ndim
-        pad[1] = (0, wc - a.shape[1])
-        return np.pad(a, pad)
+        def pad_w(a, wc):
+            if a.shape[1] > wc:  # slots above wc are inactive this chunk
+                return a[:, :wc]
+            if a.shape[1] == wc:
+                return a
+            pad = [(0, 0)] * a.ndim
+            pad[1] = (0, wc - a.shape[1])
+            return np.pad(a, pad)
 
-    def eng_w(need: int) -> int:
-        wc = bucket_w(need)
-        return dp.supported_w(wc) if use_pallas else wc
+        def eng_w(need: int) -> int:
+            wc = bucket_w(need)
+            return dp.supported_w(wc) if use_pallas else wc
 
-    w_cur = eng_w(int(row_hi[:min(chunk, p.R)].max()))
-    F = jnp.zeros(1 << w_cur, jnp.uint32).at[0].set(jnp.uint32(1) << init_id)
+        w_cur = eng_w(int(row_hi[:min(chunk, p.R)].max()))
+        F = jnp.zeros(1 << w_cur, jnp.uint32).at[0].set(
+            jnp.uint32(1) << init_id)
+    site = "dense-pallas" if use_pallas else "dense-chunk"
 
     # One blocking fetch (the dead flag) per chunk: chunks are strictly
     # sequential so there is no pipelining to lose, it exits early on a
@@ -296,53 +303,56 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
             return {"valid?": "unknown", "analyzer": "tpu-dense",
                     "error": "cancelled"}
         n = min(chunk, p.R - base)
-        w_c = eng_w(int(row_hi[base:base + n].max()))
-        if w_c > w_cur:
-            F = jnp.pad(F, (0, (1 << w_c) - (1 << w_cur)))
-            w_cur = w_c
-        if snapshots is not None:
-            if keep_all:
-                snapshots.append((base, F))
-            else:
-                snapshots[:] = [(base, F)]
-        _d0 = _monotonic()
-        if use_pallas:
-            # Bucket the kernel grid to the chunk's actual row count so a
-            # short final chunk doesn't pay for thousands of no-op steps
-            # (and don't upload the unused table tail at all).
-            n_pad = min(chunk, max(512, 1 << (n - 1).bit_length()))
-            sl = lambda a: _chunk_slice(a, base, chunk)[:n_pad]  # noqa: E731
-            masks = dp.transition_masks(
-                jnp.asarray(pad_w(sl(slot_f_h), w_cur)),
-                jnp.asarray(pad_w(sl(slot_v_h), w_cur)),
-                jnp.asarray(pad_w(sl(active_h), w_cur)),
-                jnp.int32(nil_id), ns=ns, step_fn=step_fn)
-            F, r_done, dead = dp.pallas_chunk(
-                F, jnp.int32(n), masks, jnp.asarray(sl(ret_slot_h)),
-                w=w_cur, ns=ns, chunk=n_pad, interpret=interpret)
-            # The pallas closure runs to true fixpoint (its waived
-            # unbounded loop) — no truncation channel to consult.
-            trunc = jnp.bool_(False)
-        else:
-            F, r_done, dead, trunc = _dense_chunk(
-                F, jnp.int32(n), jnp.int32(nil_id),
-                jnp.asarray(_chunk_slice(ret_slot_h, base, chunk)),
-                jnp.asarray(pad_w(_chunk_slice(active_h, base, chunk),
-                                  w_cur)),
-                jnp.asarray(pad_w(_chunk_slice(slot_f_h, base, chunk),
-                                  w_cur)),
-                jnp.asarray(pad_w(_chunk_slice(slot_v_h, base, chunk),
-                                  w_cur)),
-                w=w_cur, ns=ns, step_fn=step_fn)
-        util.progress_tick()   # liveness: one tick per decided chunk
-        # ONE blocking transfer carries both flags (the per-chunk
-        # fetch budget this engine's cost model is built on).
-        flags = np.asarray(jnp.stack([dead, trunc]))
+        with obs_trace.span("dispatch", site=site, rows=int(n)) as dsp:
+            with obs_trace.span("dense.prep"):
+                w_c = eng_w(int(row_hi[base:base + n].max()))
+                if w_c > w_cur:
+                    F = jnp.pad(F, (0, (1 << w_c) - (1 << w_cur)))
+                    w_cur = w_c
+                if snapshots is not None:
+                    if keep_all:
+                        snapshots.append((base, F))
+                    else:
+                        snapshots[:] = [(base, F)]
+                if use_pallas:
+                    # Bucket the kernel grid to the chunk's actual row
+                    # count so a short final chunk doesn't pay for
+                    # thousands of no-op steps (and don't upload the
+                    # unused table tail at all).
+                    n_pad = min(chunk, max(512, 1 << (n - 1).bit_length()))
+                    sl = lambda a: _chunk_slice(  # noqa: E731
+                        a, base, chunk)[:n_pad]
+                    masks = dp.transition_masks(
+                        jnp.asarray(pad_w(sl(slot_f_h), w_cur)),
+                        jnp.asarray(pad_w(sl(slot_v_h), w_cur)),
+                        jnp.asarray(pad_w(sl(active_h), w_cur)),
+                        jnp.int32(nil_id), ns=ns, step_fn=step_fn)
+                    F, r_done, dead = dp.pallas_chunk(
+                        F, jnp.int32(n), masks, jnp.asarray(sl(ret_slot_h)),
+                        w=w_cur, ns=ns, chunk=n_pad, interpret=interpret)
+                    # The pallas closure runs to true fixpoint (its
+                    # waived unbounded loop) — no truncation channel to
+                    # consult.
+                    trunc = jnp.bool_(False)
+                else:
+                    F, r_done, dead, trunc = _dense_chunk(
+                        F, jnp.int32(n), jnp.int32(nil_id),
+                        jnp.asarray(_chunk_slice(ret_slot_h, base, chunk)),
+                        jnp.asarray(pad_w(_chunk_slice(active_h, base,
+                                                       chunk), w_cur)),
+                        jnp.asarray(pad_w(_chunk_slice(slot_f_h, base,
+                                                       chunk), w_cur)),
+                        jnp.asarray(pad_w(_chunk_slice(slot_v_h, base,
+                                                       chunk), w_cur)),
+                        w=w_cur, ns=ns, step_fn=step_fn)
+            util.progress_tick()   # liveness: one tick per decided chunk
+            # ONE blocking transfer carries both flags (the per-chunk
+            # fetch budget this engine's cost model is built on).
+            with obs_trace.span("dense.wait"):
+                flags = np.asarray(jnp.stack([dead, trunc]))
+            dsp.note(outcome="ok")
         dead_b, trunc_b = bool(flags[0]), bool(flags[1])
-        obs_trace.complete("dispatch", _d0, _monotonic() - _d0,
-                           site="dense-pallas" if use_pallas
-                           else "dense-chunk", rows=int(n),
-                           outcome="ok")
+        obs_trace.count("dense.chunks")
         if trunc_b:
             # The closure ceiling fired with changes pending: the
             # frontier is incomplete, so neither a dead nor a live
@@ -356,7 +366,9 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
                              f"changes pending near row {base} "
                              f"(non-monotone closure edit?)"}
         if dead_b:
-            r = base + int(r_done) - 1
+            r_done = int(r_done)
+            obs_trace.count("dense.rows", r_done)
+            r = base + r_done - 1
             ret = p.ops[int(p.ret_op[r])]
             out = {"valid?": False, "analyzer": "tpu-dense",
                    "backend": "pallas" if use_pallas else "xla",
@@ -372,12 +384,14 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
                 out.update(witness.tail_replay(p, nil_id, snapshots, r,
                                                cancel=cancel))
             return out
+        obs_trace.count("dense.rows", n)
         base += n
 
+    with obs_trace.span("dense.wait"):
+        popcount = int(jnp.sum(lax.population_count(F)))
     return {"valid?": True, "analyzer": "tpu-dense",
             "backend": "pallas" if use_pallas else "xla",
-            "final-frontier-popcount": int(
-                jnp.sum(lax.population_count(F))),
+            "final-frontier-popcount": popcount,
             "configs": []}
 
 

@@ -43,6 +43,9 @@ LANE_BITS = 7
 # Sublane tiling for 32-bit types is 8 rows: minimum bitmap 8*128 words.
 MIN_W = LANE_BITS + 3
 MAX_PALLAS_W = 18          # 2**18 words = 1 MiB bitmap in VMEM
+# The kernel's explicit name: the compiled custom call and the device
+# op in a profile carry it (doc/observability.md, "Kernel names").
+KERNEL_NAME = "dense_pallas_chunk"
 
 
 @partial(jax.jit, static_argnames=("ns", "step_fn"))
@@ -150,6 +153,7 @@ def pallas_chunk(F, n_rows, masks, ret_slot, *, w, ns, chunk,
     grid = (chunk,)
     f_out, done = pl.pallas_call(
         partial(_row_kernel, w=w, ns=ns),
+        name=KERNEL_NAME,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),             # n_rows

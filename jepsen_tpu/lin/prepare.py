@@ -751,26 +751,28 @@ def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
     from jepsen_tpu.obs import trace as obs_trace
 
     t_start = time.perf_counter()
-    history = list(history)
     fast = fast_pack_enabled()
-    with obs_trace.span("prepare", events=len(history),
-                        mode="vec" if fast else "spec") as sp:
+    # Opened before list(history): it covers all of the host pack.
+    with obs_trace.span("prepare", mode="vec" if fast else "spec") as sp:
         ok_col = None
-        if fast:
-            ops, invoke_pos, return_pos, ok_col = \
-                _pair_ops_vec_arrays(history)
-        else:
-            ops = pair_ops(history)
+        with obs_trace.span("prepare.pair"):
+            history = list(history)
+            if fast:
+                ops, invoke_pos, return_pos, ok_col = \
+                    _pair_ops_vec_arrays(history)
+            else:
+                ops = pair_ops(history)
         intern = _Interner()
 
         # Per-op (f, values) interned ONCE up front — the packing walk
         # below references ops (R x W) times, never re-interning.
-        kv = _kernelize_vec(model, ops, intern) if fast else None
-        if kv is None:
-            kernel, init_state, op_f, op_v = _kernelize(
-                model, ops, intern)
-        else:
-            kernel, init_state, op_f, op_v = kv
+        with obs_trace.span("prepare.kernelize"):
+            kv = _kernelize_vec(model, ops, intern) if fast else None
+            if kv is None:
+                kernel, init_state, op_f, op_v = _kernelize(
+                    model, ops, intern)
+            else:
+                kernel, init_state, op_f, op_v = kv
 
         n = len(ops)
         if ok_col is not None:
@@ -786,57 +788,62 @@ def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
         fill_fv = kernel is not None
         packed = None
         mode = "vec"
-        if fast:
-            packed = _pack_events_vec(
-                invoke_pos, return_pos, op_f, op_v, max_window, fill_fv,
-                R)
-        if packed is None and op_v.shape[1] == 2:
-            # the native walk is specialized to 2-word values
-            mode = "native"
-            packed = _pack_events_native(
-                invoke_pos, return_pos, op_f, op_v, max_window, fill_fv,
-                R)
-        if packed is None:
-            mode = "py"
-            packed = _pack_events_py(
-                invoke_pos, return_pos, op_f, op_v, max_window, fill_fv,
-                R)
+        with obs_trace.span("prepare.walk"):
+            if fast:
+                packed = _pack_events_vec(
+                    invoke_pos, return_pos, op_f, op_v, max_window,
+                    fill_fv, R)
+            if packed is None and op_v.shape[1] == 2:
+                # the native walk is specialized to 2-word values
+                mode = "native"
+                packed = _pack_events_native(
+                    invoke_pos, return_pos, op_f, op_v, max_window,
+                    fill_fv, R)
+            if packed is None:
+                mode = "py"
+                packed = _pack_events_py(
+                    invoke_pos, return_pos, op_f, op_v, max_window,
+                    fill_fv, R)
         ret_slot, ret_op, active, slot_f, slot_v, slot_op, max_used = \
             packed
 
-        if ok_col is not None:
-            crashed = [ops[i] for i in np.flatnonzero(~ok_col).tolist()]
-        else:
-            crashed = [o for o in ops if o.return_pos is None]
+        with obs_trace.span("prepare.tables"):
+            if ok_col is not None:
+                crashed = [ops[i]
+                           for i in np.flatnonzero(~ok_col).tolist()]
+            else:
+                crashed = [o for o in ops if o.return_pos is None]
 
-        # Per-slot crashed mask. CONSUMED BY THE DEVICE ENGINES: the
-        # crashed-op canonical chains (reduction_tables) and the sparse
-        # engine's crashed-subset dominance prune (bfs.expansion_tables
-        # builds its key-space crash masks from this; bfs.check_packed
-        # gates the prune on it) — its semantics ("this active slot's op
-        # never returns") are exactness-critical, not just reporting.
-        # Sentinel append: slot_op = -1 (inactive) wraps to a live value,
-        # and the & active keeps those cells False, matching the old
-        # masked scatter exactly.
-        ret_ext = np.concatenate(
-            [return_pos.astype(np.int32, copy=False),
-             np.zeros(1, np.int32)])
-        crashed_tbl = (ret_ext[slot_op] < 0) & active
+            # Per-slot crashed mask. CONSUMED BY THE DEVICE ENGINES: the
+            # crashed-op canonical chains (reduction_tables) and the
+            # sparse engine's crashed-subset dominance prune
+            # (bfs.expansion_tables builds its key-space crash masks
+            # from this; bfs.check_packed gates the prune on it) — its
+            # semantics ("this active slot's op never returns") are
+            # exactness-critical, not just reporting. Sentinel append:
+            # slot_op = -1 (inactive) wraps to a live value, and the &
+            # active keeps those cells False, matching the old masked
+            # scatter exactly.
+            ret_ext = np.concatenate(
+                [return_pos.astype(np.int32, copy=False),
+                 np.zeros(1, np.int32)])
+            crashed_tbl = (ret_ext[slot_op] < 0) & active
 
-        W = max(1, max_used)
-        out = PackedHistory(
-            model=model, kernel=kernel, ops=ops, window=W, R=R,
-            ret_slot=ret_slot, ret_op=ret_op,
-            active=active[:, :W], slot_f=slot_f[:, :W],
-            slot_v=slot_v[:, :W], slot_op=slot_op[:, :W],
-            crashed=crashed_tbl[:, :W],
-            init_state=init_state, intern=intern.ids,
-            unintern=intern.values, crashed_ops=crashed)
-        # Per-op interned tables ride along for the vectorized chain
-        # core (reduction_tables); views rebuilt elsewhere (service
-        # codec, stream packer) recover them from the slot tables.
-        out._op_fv = (op_f, op_v, invoke_pos)
-        sp.note(n_ops=n, R=R, W=W, walk=mode)
+            W = max(1, max_used)
+            out = PackedHistory(
+                model=model, kernel=kernel, ops=ops, window=W, R=R,
+                ret_slot=ret_slot, ret_op=ret_op,
+                active=active[:, :W], slot_f=slot_f[:, :W],
+                slot_v=slot_v[:, :W], slot_op=slot_op[:, :W],
+                crashed=crashed_tbl[:, :W],
+                init_state=init_state, intern=intern.ids,
+                unintern=intern.values, crashed_ops=crashed)
+            # Per-op interned tables ride along for the vectorized
+            # chain core (reduction_tables); views rebuilt elsewhere
+            # (service codec, stream packer) recover them from the slot
+            # tables.
+            out._op_fv = (op_f, op_v, invoke_pos)
+        sp.note(events=len(history), n_ops=n, R=R, W=W, walk=mode)
     _pack_stats["prepare_s"] += time.perf_counter() - t_start
     _pack_stats["prepare_calls"] += 1
     _pack_stats["mode"] = mode

@@ -200,6 +200,7 @@ def _dedup_call(keys, n_pad):
     K = n_pad.bit_length() - 1
     out, total = pl.pallas_call(
         partial(_dedup_body, S=S, K=K),
+        name="psort_dedup",
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
@@ -287,6 +288,7 @@ def _dedup2_call(hi, lo, n_pad):
     K = n_pad.bit_length() - 1
     out_hi, out_lo, total = pl.pallas_call(
         partial(_dedup2_body, S=S, K=K),
+        name="psort_dedup2",
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -409,6 +411,7 @@ def _dedup_dom_call(a, w, cmask, rmask, n_pad, force=False):
     masks = jnp.stack([cmask, rmask]).astype(jnp.uint32)
     out, total = pl.pallas_call(
         partial(_dedup_dom_body, S=S, K=K, force=force),
+        name="psort_dedup_dom",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
@@ -596,6 +599,7 @@ def _dedup2_dom_call(a_hi, a_lo, w_hi, w_lo, masks, n_pad, force=False):
     K = n_pad.bit_length() - 1
     out_hi, out_lo, total = pl.pallas_call(
         partial(_dedup2_dom_body, S=S, K=K, force=force),
+        name="psort_dedup2_dom",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * 4,
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -663,6 +667,7 @@ def _compact_call(keys, n_pad):
     K = n_pad.bit_length() - 1
     out, total = pl.pallas_call(
         partial(_compact_body, S=S, K=K),
+        name="psort_compact",
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.SMEM)],
@@ -707,6 +712,7 @@ def _compact2_call(hi, lo, n_pad):
     K = n_pad.bit_length() - 1
     out_hi, out_lo, total = pl.pallas_call(
         partial(_compact2_body, S=S, K=K),
+        name="psort_compact2",
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -295,6 +295,7 @@ def _fixpoint_call(lo, hi, count, cols, sats, *, cap, b, it_max, pair,
     outs = pl.pallas_call(
         partial(_fixpoint_body, SP=SP, S0=S0, M=M, K=K, b=b, cap=cap,
                 it_max=it_max, pair=pair),
+        name="psort_fused_fixpoint",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * (2 if pair else 1),
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]

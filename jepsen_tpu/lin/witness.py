@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from jepsen_tpu.lin import cpu, dense
 from jepsen_tpu.lin.prepare import PackedHistory
+from jepsen_tpu.obs import trace as obs_trace
 
 
 def replay_configs(p: PackedHistory, configs: set, base: int,
@@ -37,15 +38,18 @@ def replay_configs(p: PackedHistory, configs: set, base: int,
     render at checker.clj:96-103)."""
     if not configs:
         return {}
+    obs_trace.count("witness.configs", len(configs))
+    obs_trace.count("witness.rows", dead_row - base + 1)
     order = {cfg: None for cfg in configs}
-    try:
-        cpu.search_rows(p, configs, order, base, dead_row + 1,
-                        cancel=cancel)
-    except cpu.Dead as d:
-        return {"configs": cpu._decode_configs(p, d.seen, d.r),
-                "final-paths": cpu._final_paths(p, d.seen, d.order)}
-    except Exception:
-        return {}
+    with obs_trace.span("witness.replay"):
+        try:
+            cpu.search_rows(p, configs, order, base, dead_row + 1,
+                            cancel=cancel)
+        except cpu.Dead as d:
+            return {"configs": cpu._decode_configs(p, d.seen, d.r),
+                    "final-paths": cpu._final_paths(p, d.seen, d.order)}
+        except Exception:
+            return {}
     # The tail replay survived where the device died: a disagreement
     # between engines — surface it rather than fabricate a path.
     return {"error": "tail replay disagrees with device verdict "
@@ -58,12 +62,15 @@ def tail_replay(p: PackedHistory, nil_id: int, snapshots: list,
     at or before ``dead_row`` and replay the failing tail.
     ``cancel`` keeps a competition loser's replay from blocking the
     race join."""
-    usable = [(b, F) for b, F in snapshots if b <= dead_row]
-    if not usable:
-        return {}
-    base, F = usable[-1]
-    configs = {(bits, st) for bits, st in dense.decode_bitmap(F, nil_id)}
-    return replay_configs(p, configs, base, dead_row, cancel=cancel)
+    with obs_trace.span("witness"):
+        usable = [(b, F) for b, F in snapshots if b <= dead_row]
+        if not usable:
+            return {}
+        base, F = usable[-1]
+        with obs_trace.span("witness.decode"):
+            configs = {(bits, st)
+                       for bits, st in dense.decode_bitmap(F, nil_id)}
+        return replay_configs(p, configs, base, dead_row, cancel=cancel)
 
 
 def tail_replay_sparse(p: PackedHistory, snapshots: list,
@@ -73,16 +80,18 @@ def tail_replay_sparse(p: PackedHistory, snapshots: list,
     frontiers; decode the multi-word bitsets and replay the tail."""
     import numpy as np
 
-    usable = [s for s in snapshots if s[0] <= dead_row]
-    if not usable:
-        return {}
-    base, bits, state, count = usable[-1]
-    n = min(int(count), np.asarray(bits).shape[0])
-    bits = np.asarray(bits)[:n].astype(object)
-    state = np.asarray(state)[:n]
-    packed = bits[:, 0]
-    for w in range(1, bits.shape[1]):
-        packed = packed | (bits[:, w] << (32 * w))
-    configs = set(zip((int(b) for b in packed),
-                      map(tuple, state.tolist())))
-    return replay_configs(p, configs, base, dead_row, cancel=cancel)
+    with obs_trace.span("witness"):
+        usable = [s for s in snapshots if s[0] <= dead_row]
+        if not usable:
+            return {}
+        base, bits, state, count = usable[-1]
+        with obs_trace.span("witness.decode"):
+            n = min(int(count), np.asarray(bits).shape[0])
+            bits = np.asarray(bits)[:n].astype(object)
+            state = np.asarray(state)[:n]
+            packed = bits[:, 0]
+            for w in range(1, bits.shape[1]):
+                packed = packed | (bits[:, w] << (32 * w))
+            configs = set(zip((int(b) for b in packed),
+                              map(tuple, state.tolist())))
+        return replay_configs(p, configs, base, dead_row, cancel=cancel)

@@ -1,29 +1,45 @@
-"""Span tracer: the flight recorder's event source.
+"""Span tracer: the flight recorder's event source, and the per-request
+span summary every verdict carries.
 
-A thread-safe, env-gated (``JEPSEN_TPU_TRACE=1``) tracer with a
-context-manager API over monotonic clocks::
+A thread-safe tracer with a context-manager API over monotonic clocks::
 
     from jepsen_tpu.obs import trace
     with trace.span("dispatch", site="host-fixpoint", cap=4096) as sp:
         ...
         sp.note(outcome="ok", passes=7)
 
-Disabled (the default), :func:`span` returns one shared
-:data:`NULL_SPAN` object — no span object, no event, no buffer touch
-per call — so the quick tier and untraced production runs pay only an
-``os.environ`` lookup. Enabled, completed spans land in a bounded
-in-memory buffer (``JEPSEN_TPU_TRACE_BUF`` events) that SPILLS to a
-JSONL file (``JEPSEN_TPU_TRACE_FILE``, default
-``<repo>/.jax_cache/trace.jsonl``; ``0`` disables the file) instead of
-dropping — a killed run keeps everything already spilled, and
-``atexit`` flushes the tail. One process per file: the first write of
-a process truncates it, so ``cli.py trace report`` reads the most
-recent run.
+Two things make a span real; otherwise :func:`span` returns one shared
+:data:`NULL_SPAN` object (no span object, no event, no buffer touch),
+so untraced code outside a request pays a context-variable read and an
+``os.environ`` lookup:
+
+- **A request.** :func:`request` opens one per ``lin.analysis`` call:
+  an integer id and a root span named ``analysis``, held in a context
+  variable, so threads started with ``contextvars.copy_context().run``
+  report into it too. Each span closed inside it adds its count, its
+  duration and its self time (duration less what its child spans
+  cover) to the request's summary, and :func:`count` adds to its
+  counters; :meth:`Request.record` is the ``spans``/``counts`` pair the
+  verdict carries. Each in-request span is also a
+  ``jax.profiler.TraceAnnotation`` named ``jepsen.<span>`` with the
+  request id as metadata — on the profiler's clock, beside the
+  device's ops, in a profiled run (jax is imported lazily, and only
+  once the program has imported it).
+- **The JSONL recorder** (``JEPSEN_TPU_TRACE=1``). Completed spans
+  land in a bounded in-memory buffer (``JEPSEN_TPU_TRACE_BUF``
+  events) that SPILLS to a JSONL file (``JEPSEN_TPU_TRACE_FILE``,
+  default ``<repo>/.jax_cache/trace.jsonl``; ``0`` disables the file)
+  instead of dropping — a killed run keeps everything already
+  spilled, and ``atexit`` flushes the tail. One process per file: the
+  first write of a process truncates it, so ``cli.py trace report``
+  reads the most recent run.
 
 Event shape (one JSON object per line)::
 
     {"name": ..., "ph": "X"|"i", "ts": <monotonic s>, "dur": <s>,
-     "pid": ..., "tid": ..., "depth": <span nesting>, "args": {...}}
+     "pid": ..., "tid": ..., "depth": <span nesting>,
+     "req": <request id or null>, "parent": <enclosing span's name or
+     null>, "args": {...}}
 
 Timestamps are ``time.monotonic()`` seconds (relative, clock-nemesis
 immune); :func:`jepsen_tpu.obs.report.to_chrome` converts to the
@@ -36,8 +52,11 @@ behaviour.
 from __future__ import annotations
 
 import atexit
+import contextvars
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -100,6 +119,12 @@ _file_started = False
 _file_dead = False
 _atexit_on = False
 _tls = threading.local()
+# The innermost open real span of this context (thread, or a copy of a
+# context run in another thread); its ``req`` is the open request.
+_current: contextvars.ContextVar = contextvars.ContextVar(
+    "jepsen_tpu_span", default=None)
+_request_ids = itertools.count(1)
+_annotation_cls = None
 
 
 class _NullSpan:
@@ -120,54 +145,156 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str, req: int):
+    """A ``jax.profiler.TraceAnnotation`` for an in-request span, or
+    None while the program has not imported jax (``obs/`` stays
+    jax-free at import time). With no profiler session it costs one
+    native check."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(f"jepsen.{name}", req=req)
+
+
 class Span:
     """One traced interval. Use via :func:`span` as a context manager;
     ``note(**kw)`` merges attributes into the event's ``args`` (e.g.
     the outcome, pass counts). An exception exiting the span stamps
     ``outcome="error:<Type>"`` unless the site noted one already."""
 
-    __slots__ = ("name", "meta", "_t0")
+    __slots__ = ("name", "meta", "req", "parent", "depth", "child_s",
+                 "_record", "_t0", "_token", "_ann")
 
-    def __init__(self, name: str, meta: dict):
+    def __init__(self, name: str, meta: dict, req, parent, record: bool):
         self.name = name
         self.meta = meta
+        self.req = req
+        self.parent = parent
+        self.depth = 0 if parent is None else parent.depth + 1
+        self.child_s = 0.0
+        self._record = record
         self._t0 = 0.0
+        self._token = None
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = time.monotonic()
-        stack = getattr(_tls, "stack", None)
-        if stack is None:
-            stack = _tls.stack = []
-        stack.append(self)
+        if self.req is not None:
+            self._ann = _annotation(self.name, self.req.id)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self._token = _current.set(self)
+        self._t0 = time.perf_counter()
         return self
 
     def note(self, **kw):
         self.meta.update(kw)
 
     def __exit__(self, et, ev, tb):
-        end = time.monotonic()
-        stack = getattr(_tls, "stack", None)
-        depth = 0
-        if stack:
-            try:
-                stack.remove(self)
-            except ValueError:
-                pass
-            depth = len(stack)
-        if et is not None and "outcome" not in self.meta:
-            self.meta["outcome"] = f"error:{et.__name__}"
-        _record({"name": self.name, "ph": "X", "ts": self._t0,
-                 "dur": end - self._t0, "pid": os.getpid(),
-                 "tid": threading.get_ident(), "depth": depth,
-                 "args": self.meta})
+        dur = time.perf_counter() - self._t0
+        _current.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        req = self.req
+        if req is not None:
+            with req.lock:
+                if self.parent is not None:
+                    self.parent.child_s += dur
+                req.add(self.name, dur, dur - self.child_s)
+        if self._record:
+            if et is not None and "outcome" not in self.meta:
+                self.meta["outcome"] = f"error:{et.__name__}"
+            _record({"name": self.name, "ph": "X",
+                     "ts": time.monotonic() - dur, "dur": dur,
+                     "pid": os.getpid(), "tid": threading.get_ident(),
+                     "depth": self.depth,
+                     "req": None if req is None else req.id,
+                     "parent": None if self.parent is None
+                     else self.parent.name,
+                     "args": self.meta})
         return False
 
 
 def span(name: str, **meta):
-    """A new :class:`Span` (or :data:`NULL_SPAN` when tracing is off)."""
-    if not enabled():
+    """A new :class:`Span`: inside a request, or with the JSONL
+    recorder on; else :data:`NULL_SPAN`."""
+    cur = _current.get()
+    req = None if cur is None else cur.req
+    rec = enabled()
+    if req is None and not rec:
         return NULL_SPAN
-    return Span(name, meta)
+    return Span(name, meta, req, cur, rec)
+
+
+class Request:
+    """One checker request's record: ``spans`` maps each span name to
+    ``{"n", "s", "self_s"}`` (count, seconds, self seconds) and
+    ``counts`` each counter to its total. Open with :func:`request`;
+    read :meth:`record` once it has closed."""
+
+    __slots__ = ("id", "spans", "counts", "lock", "_root", "_compiles")
+
+    def __init__(self):
+        self.id = next(_request_ids)
+        self.spans: dict = {}
+        self.counts: dict = {}
+        self.lock = threading.Lock()
+        self._root = None
+        self._compiles = None
+
+    def add(self, name: str, dur: float, self_s: float) -> None:
+        # Children on parallel threads (the competition racers) may
+        # overlap their parent: its self time never reads below zero.
+        self_s = max(self_s, 0.0)
+        e = self.spans.get(name)
+        if e is None:
+            self.spans[name] = {"n": 1, "s": dur, "self_s": self_s}
+        else:
+            e["n"] += 1
+            e["s"] += dur
+            e["self_s"] += self_s
+
+    def count(self, name: str, n: int) -> None:
+        with self.lock:
+            self.counts[name] = self.counts.get(name, 0) + n
+
+    def __enter__(self):
+        self._compiles = util.compile_counts()
+        self._root = Span("analysis", {}, self, _current.get(), enabled())
+        self._root.__enter__()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self._root.__exit__(et, ev, tb)
+        c0, c1 = self._compiles, util.compile_counts()
+        if c0 is not None and c1 is not None:
+            self.count("compile_requests", c1[0] - c0[0])
+            self.count("compiles", c1[1] - c0[1])
+        return False
+
+    def record(self) -> dict:
+        """``{"spans": {...}, "counts": {...}}``: copies, for the
+        verdict."""
+        with self.lock:
+            return {"spans": {k: dict(v) for k, v in self.spans.items()},
+                    "counts": dict(self.counts)}
+
+
+def request() -> Request:
+    """A new request scope (a context manager): see the module
+    docstring."""
+    return Request()
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to counter ``name`` of the open request; nothing
+    outside one."""
+    cur = _current.get()
+    if cur is not None and cur.req is not None:
+        cur.req.count(name, int(n))
 
 
 def tail_note(**kw) -> None:
@@ -190,7 +317,7 @@ def complete(name: str, t0: float, dur_s: float, **meta) -> None:
         return
     _record({"name": name, "ph": "X", "ts": t0, "dur": dur_s,
              "pid": os.getpid(), "tid": threading.get_ident(),
-             "depth": 0, "args": meta})
+             "depth": 0, **_where(), "args": meta})
 
 
 def instant(name: str, **meta) -> None:
@@ -200,7 +327,17 @@ def instant(name: str, **meta) -> None:
         return
     _record({"name": name, "ph": "i", "ts": time.monotonic(),
              "dur": 0.0, "pid": os.getpid(),
-             "tid": threading.get_ident(), "depth": 0, "args": meta})
+             "tid": threading.get_ident(), "depth": 0, **_where(),
+             "args": meta})
+
+
+def _where() -> dict:
+    """``req`` and ``parent`` of an event recorded at this point."""
+    cur = _current.get()
+    if cur is None:
+        return {"req": None, "parent": None}
+    return {"req": None if cur.req is None else cur.req.id,
+            "parent": cur.name}
 
 
 def _record(ev: dict) -> None:
@@ -320,7 +457,6 @@ def reset() -> None:
         _file_started = False
         _file_dead = False
     _tls.last = None
-    _tls.stack = []
 
 
 # XLA compiles as trace events: the compile meter (util) runs the hook

@@ -386,6 +386,15 @@ def compile_meter() -> dict:
             "xla_cache_hits": hits}
 
 
+def compile_counts() -> tuple[int, int] | None:
+    """``(requests, compiles)`` so far: in-memory jit misses that asked
+    for a compile, and those that reached XLA (persistent-cache hits
+    are the difference). None while the meter is not installed."""
+    if not _compile_meter["installed"]:
+        return None
+    return _compile_meter["gets"], _compile_meter["n"]
+
+
 def cache_dir() -> str:
     """``<repo>/.jax_cache`` — the one anchor for every on-disk
     artifact (compile cache, quarantine ledger, service stats, trace

@@ -8,6 +8,8 @@ fixture, never at import time: only one process may load the TPU
 library, and every xdist worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,11 +53,61 @@ def _shape(chip, shape, dtype=jnp.uint32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
-def _compile(lowered):
-    # A Pallas TPU kernel lowers to a Mosaic custom call; the
-    # interpreter would lower to plain HLO and prove nothing.
-    assert "tpu_custom_call" in lowered.as_text()
-    return lowered.compile()
+# Compiled text by kernel and shape: the name checks below read what
+# the compile tests already built instead of compiling it again.
+_TEXT: dict = {}
+
+
+def _compile(key, lower):
+    if key not in _TEXT:
+        lowered = lower()
+        # A Pallas TPU kernel lowers to a Mosaic custom call; the
+        # interpreter would lower to plain HLO and prove nothing.
+        assert "tpu_custom_call" in lowered.as_text()
+        _TEXT[key] = lowered.compile().as_text()
+    return _TEXT[key]
+
+
+def _dense(chip, w, ns):
+    chunk = 512
+    return _compile(("dense", w, ns), lambda: dense_pallas.pallas_chunk.lower(
+        _shape(chip, (1 << w,)), _shape(chip, (), jnp.int32),
+        _shape(chip, (chunk, w, ns)), _shape(chip, (chunk,), jnp.int32),
+        w=w, ns=ns, chunk=chunk))
+
+
+def _fused(chip, pair):
+    cap, m, b = 1 << 10, 7, 6
+    return _compile(("fused", pair), lambda: psort_fused._fixpoint_call.lower(
+        _shape(chip, (cap,)), _shape(chip, (cap,)) if pair else None,
+        _shape(chip, (), jnp.int32),
+        _shape(chip, (psort_fused.N_COL_ROWS, m)),
+        _shape(chip, (2, 1 << b)), cap=cap, b=b, it_max=64, pair=pair,
+        M=m))
+
+
+# Each psort kernel by its explicit name, at a small size (the dedup at
+# the size its compile test has always used).
+_N = 1 << 10
+_PSORT = {
+    "psort_dedup": lambda c: psort._dedup_call.lower(
+        _shape(c, (1 << 14,)), n_pad=1 << 14),
+    "psort_dedup2": lambda c: psort._dedup2_call.lower(
+        _shape(c, (_N,)), _shape(c, (_N,)), n_pad=_N),
+    "psort_dedup_dom": lambda c: psort._dedup_dom_call.lower(
+        _shape(c, (_N,)), _shape(c, (_N,)), _shape(c, ()), _shape(c, ()),
+        n_pad=_N),
+    "psort_dedup2_dom": lambda c: psort._dedup2_dom_call.lower(
+        *[_shape(c, (_N,))] * 4, _shape(c, (4,)), n_pad=_N),
+    "psort_compact": lambda c: psort._compact_call.lower(
+        _shape(c, (_N,)), n_pad=_N),
+    "psort_compact2": lambda c: psort._compact2_call.lower(
+        _shape(c, (_N,)), _shape(c, (_N,)), n_pad=_N),
+}
+
+
+def _psort(chip, name):
+    return _compile((name,), lambda: _PSORT[name](chip))
 
 
 @pytest.mark.parametrize("w,ns", [(HEADLINE_W, 8),
@@ -63,24 +115,40 @@ def _compile(lowered):
 def test_dense_pallas_chunk_compiles(chip, w, ns):
     # MAX_PALLAS_W at the smallest state bucket: the kernel's compile
     # time grows with w * ns, and the width is what the bound is about.
-    chunk = 512
-    _compile(dense_pallas.pallas_chunk.lower(
-        _shape(chip, (1 << w,)), _shape(chip, (), jnp.int32),
-        _shape(chip, (chunk, w, ns)), _shape(chip, (chunk,), jnp.int32),
-        w=w, ns=ns, chunk=chunk))
+    _dense(chip, w, ns)
 
 
 def test_psort_dedup_compiles(chip, mosaic):
-    n = 1 << 14
-    _compile(psort._dedup_call.lower(_shape(chip, (n,)), n_pad=n))
+    _psort(chip, "psort_dedup")
 
 
 @pytest.mark.parametrize("pair", [False, True])
 def test_fused_fixpoint_compiles(chip, mosaic, pair):
-    cap, m, b = 1 << 10, 7, 6
-    _compile(psort_fused._fixpoint_call.lower(
-        _shape(chip, (cap,)), _shape(chip, (cap,)) if pair else None,
-        _shape(chip, (), jnp.int32),
-        _shape(chip, (psort_fused.N_COL_ROWS, m)),
-        _shape(chip, (2, 1 << b)), cap=cap, b=b, it_max=64, pair=pair,
-        M=m))
+    _fused(chip, pair)
+
+
+def _custom_calls(text):
+    """Instruction names of the compiled program's Mosaic calls: the
+    names a profile's device ops carry."""
+    return [m.group(1) for m in
+            re.finditer(r"%([\w.\-]+) = [^\n]*custom-call", text)]
+
+
+def _named(text, name):
+    return any(re.fullmatch(rf"{re.escape(name)}(\.\d+)?", c)
+               for c in _custom_calls(text))
+
+
+def test_dense_kernel_carries_its_name(chip):
+    text = _dense(chip, HEADLINE_W, 8)
+    assert _named(text, dense_pallas.KERNEL_NAME), _custom_calls(text)
+    # The benchmark finds the dense kernel's device time by this
+    # substring of the op's name.
+    assert "pallas_chunk" in dense_pallas.KERNEL_NAME
+
+
+@pytest.mark.parametrize("name", sorted(_PSORT) + ["psort_fused_fixpoint"])
+def test_psort_kernels_carry_their_names(chip, mosaic, name):
+    text = _fused(chip, False) if name == "psort_fused_fixpoint" \
+        else _psort(chip, name)
+    assert _named(text, name), _custom_calls(text)

@@ -221,6 +221,173 @@ def test_spill_failure_latches_to_in_memory_ring(tmp_path, monkeypatch):
     assert evs[-1]["args"]["i"] == trace._SPILL_BATCH + 199
 
 
+# --- tracer: request scope --------------------------------------------------
+
+
+def test_in_request_span_records_into_the_summary_untraced():
+    # JEPSEN_TPU_TRACE unset: outside a request span() stays the null
+    # object; inside one it is real and feeds the request's summary,
+    # with self time = duration less what child spans cover.
+    assert not trace.enabled()
+    assert trace.span("outside") is trace.NULL_SPAN
+    trace.count("outside")
+    with trace.request() as req:
+        with trace.span("outer"):
+            with trace.span("inner"):
+                time.sleep(0.002)
+            trace.count("things", 3)
+        trace.count("things")
+    assert trace.span("after") is trace.NULL_SPAN
+    rec = req.record()
+    spans, counts = rec["spans"], rec["counts"]
+    assert set(spans) == {"analysis", "outer", "inner"}
+    assert counts["things"] == 4 and "outside" not in counts
+    inner, outer, root = spans["inner"], spans["outer"], spans["analysis"]
+    assert inner["n"] == outer["n"] == root["n"] == 1
+    assert inner["s"] >= 0.002 and inner["self_s"] == inner["s"]
+    assert outer["self_s"] == pytest.approx(outer["s"] - inner["s"])
+    assert sum(v["self_s"] for v in spans.values()) == \
+        pytest.approx(root["s"])
+    assert trace.events() == []       # the JSONL recorder stayed off
+
+
+def test_request_summary_loses_no_update_across_threads():
+    # Threads run in copies of the request's context (as the
+    # competition racers do) and update one summary; a lost update
+    # would show in the totals.
+    import contextvars
+    import sys
+
+    n_threads, n_spans = 24, 200
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with trace.request() as req:
+            def work():
+                for _ in range(n_spans):
+                    with trace.span("racer"):
+                        trace.count("ticks")
+
+            ts = [threading.Thread(target=contextvars.copy_context().run,
+                                   args=(work,))
+                  for _ in range(n_threads)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    rec = req.record()
+    assert rec["spans"]["racer"]["n"] == n_threads * n_spans
+    assert rec["counts"]["ticks"] == n_threads * n_spans
+    # The root's self time never reads below zero, though the racers'
+    # spans together may cover more than its duration.
+    assert rec["spans"]["analysis"]["self_s"] >= 0
+
+
+def test_request_spans_reach_the_recorder_with_req_and_parent(monkeypatch):
+    _on(monkeypatch)
+    with trace.request() as req:
+        with trace.span("prepare"):
+            pass
+    prep, root = trace.events()
+    assert prep["name"] == "prepare" and root["name"] == "analysis"
+    assert prep["req"] == root["req"] == req.id
+    assert prep["parent"] == "analysis" and root["parent"] is None
+    assert prep["depth"] == 1 and root["depth"] == 0
+
+
+def test_request_spans_are_profiler_annotations(tmp_path):
+    # Inside a profiler session the in-request spans lie on the host
+    # plane as jepsen.<span>, nested in an enclosing annotation, on one
+    # clock.
+    import jax
+    from jax.profiler import ProfileData
+
+    from jepsen_tpu import models as m
+    from jepsen_tpu.lin import prepare, synth
+
+    h = synth.generate_register_history(600, concurrency=4, seed=3,
+                                        value_range=3, crash_prob=0.05,
+                                        max_crashes=6)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("outer"):
+            with trace.request() as req:
+                prepare.prepare(m.cas_register(), h)
+    pb, = tmp_path.glob("**/*.xplane.pb")
+    found: dict = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("outer", "jepsen.analysis", "jepsen.prepare",
+                              "jepsen.prepare.pair"):
+                    found[e.name] = (e.start_ns, e.end_ns, dict(e.stats))
+    (o0, o1, _), (p0, p1, stats) = found["outer"], found["jepsen.prepare"]
+    assert o0 <= p0 < p1 <= o1
+    assert stats.get("req") == req.id
+    a0, a1, _ = found["jepsen.analysis"]
+    q0, q1, _ = found["jepsen.prepare.pair"]
+    assert o0 <= a0 <= p0 <= q0 < q1 <= p1 <= a1 <= o1
+
+
+def _history_600(corrupt: bool):
+    from jepsen_tpu.lin import synth
+
+    h = synth.generate_register_history(600, concurrency=4, seed=3,
+                                        value_range=3, crash_prob=0.05,
+                                        max_crashes=6)
+    return synth.corrupt_history(h, seed=3) if corrupt else h
+
+
+@pytest.mark.compiles
+@pytest.mark.parametrize("corrupt", [False, True])
+def test_verdict_carries_the_request_record(corrupt):
+    # Every lin.analysis verdict carries spans and counts; on this
+    # single-threaded request the self times add up to the root's
+    # duration, and dense.rows counts the rows swept: all of a valid
+    # history's, an invalid one's up to its dead row, which the witness
+    # then replays from its chunk's entry bitmap.
+    from jepsen_tpu import checker, models as m
+    from jepsen_tpu.lin import prepare
+
+    h = _history_600(corrupt)
+    r = checker.linearizable(algorithm="tpu").check(
+        None, m.cas_register(), h, {})
+    spans, counts = r["spans"], r["counts"]
+    assert r["valid?"] is (not corrupt) and r["analyzer"] == "tpu-dense"
+    assert {"analysis", "prepare", "prepare.pair", "prepare.kernelize",
+            "prepare.walk", "prepare.tables", "check", "dense.prep",
+            "dense.wait", "dispatch"} <= set(spans)
+    assert sum(v["self_s"] for v in spans.values()) == pytest.approx(
+        spans["analysis"]["s"], abs=1e-3)
+    assert spans["dispatch"]["n"] == counts["dense.chunks"] >= 1
+    rows = r["dead-row"] + 1 if corrupt else \
+        prepare.prepare(m.cas_register(), h).R
+    assert counts["dense.rows"] == rows
+    assert counts["compile_requests"] >= counts["compiles"] >= 0
+    if corrupt:
+        assert r["final-paths"]
+        assert {"witness", "witness.decode", "witness.replay"} <= set(spans)
+        assert counts["witness.rows"] == rows      # one chunk, base 0
+        assert counts["witness.configs"] >= 1
+    else:
+        assert not {"witness", "witness.rows"} & (set(spans) | set(counts))
+
+
+@pytest.mark.compiles
+def test_competition_racers_report_into_the_request():
+    # The racer threads run in copies of the request's context: the
+    # device racer's check span reaches the verdict's record.
+    from jepsen_tpu import lin, models as m
+
+    r = lin.analysis(m.cas_register(), _history_600(False))
+    assert r["valid?"] is True
+    assert {"analysis", "prepare", "check"} <= set(r["spans"])
+
+
 # --- chrome export ----------------------------------------------------------
 
 
@@ -716,7 +883,11 @@ def test_e2e_traced_run_attribution_and_parity(monkeypatch):
     # row sums against, dispatch_s + host_other_s) is within 5% of the
     # measured call wall.
     assert agg["checks"] == 1
-    assert agg["total_s"] == pytest.approx(wall, rel=0.05)
+    # total_s is rounded to the millisecond, which alone is more than
+    # 5% of a run this short: compare the span's own duration.
+    check_s, = [e["dur"] for e in evs if e["name"] == "check"]
+    assert agg["total_s"] == pytest.approx(check_s, abs=5e-4)
+    assert check_s == pytest.approx(wall, rel=0.05)
     assert agg["dispatch_s"] + agg["host_other_s"] == pytest.approx(
         agg["total_s"], abs=0.01)      # each term rounded to 3 digits
     assert agg["dispatches"] >= 1 and agg["sites"]
