@@ -283,6 +283,7 @@ def check_generic(p: PackedHistory, witness: bool = False) -> dict:
     def shim(o) -> Op:
         return Op("invoke", o.f, o.value, o.process)
 
+    ops = list(p.ops)       # read per config and slot: index a list
     for r in range(p.R):
         act = p.active[r]
         seen = set(configs)
@@ -293,7 +294,7 @@ def check_generic(p: PackedHistory, witness: bool = False) -> dict:
                 bits, st = cfg
                 for j in range(p.window):
                     if act[j] and not (bits >> j) & 1:
-                        o = p.ops[int(p.slot_op[r, j])]
+                        o = ops[int(p.slot_op[r, j])]
                         st2 = st.step(shim(o))
                         if not is_inconsistent(st2):
                             c2 = (bits | (1 << j), st2)

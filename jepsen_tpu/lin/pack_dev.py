@@ -50,6 +50,7 @@ from __future__ import annotations
 import hashlib
 import os
 import time
+from collections.abc import Sequence
 from dataclasses import dataclass
 from typing import Any
 
@@ -113,7 +114,7 @@ class PrePacked:
 
     model: Any
     kernel: Any                  # KernelModel | None
-    ops: list                    # LinOp list (reporting / witnesses)
+    ops: Sequence                # LinOps (reporting / witnesses)
     window: int                  # W_used (exact, from the depth scan)
     R: int
     n: int
@@ -121,11 +122,10 @@ class PrePacked:
     return_pos: np.ndarray       # i32[n]  (-1 = crashed)
     op_f: np.ndarray             # i32[n]
     op_v: np.ndarray             # i32[n, vw]
-    ok_col: np.ndarray | None    # bool[n] (None on the spec pairing)
     init_state: np.ndarray
     intern: dict
     unintern: list
-    crashed_ops: list
+    crashed_idx: list            # indices into ops of the crashed ops
 
     @property
     def state_width(self) -> int:
@@ -144,34 +144,14 @@ def prepack(model, history,
     history = list(history)
     fast = prepare.fast_pack_enabled()
     with obs_trace.span("prepack", events=len(history)) as sp:
-        ok_col = None
-        if fast:
-            ops, invoke_pos, return_pos, ok_col = \
-                prepare._pair_ops_vec_arrays(history)
-        else:
-            ops = prepare.pair_ops(history)
+        ops, invoke_pos, return_pos, ok = prepare._pair(history, fast)
         intern = prepare._Interner()
-        kv = prepare._kernelize_vec(model, ops, intern) if fast else None
-        if kv is None:
-            kernel, init_state, op_f, op_v = prepare._kernelize(
-                model, ops, intern)
-        else:
-            kernel, init_state, op_f, op_v = kv
+        kernel, init_state, op_f, op_v = prepare._kernelize_ops(
+            model, ops, intern)
         n = len(ops)
-        if ok_col is not None:
-            R = int(ok_col.sum())
-        else:
-            R = sum(1 for o in ops if o.ok)
-            invoke_pos = np.fromiter(
-                (o.invoke_pos for o in ops), np.int32, n)
-            return_pos = np.fromiter(
-                (-1 if o.return_pos is None else o.return_pos
-                 for o in ops), np.int32, n)
+        R = int(ok.sum())
         W_used = _window_scan(invoke_pos, return_pos, max_window)
-        if ok_col is not None:
-            crashed = [ops[i] for i in np.flatnonzero(~ok_col).tolist()]
-        else:
-            crashed = [o for o in ops if o.return_pos is None]
+        crashed = np.flatnonzero(~ok).tolist()
         sp.note(n_ops=n, R=R, W=W_used)
     st = prepare._pack_stats
     st["prepare_s"] += time.perf_counter() - t0
@@ -182,8 +162,8 @@ def prepack(model, history,
         return_pos=np.asarray(return_pos, np.int32),
         op_f=np.asarray(op_f, np.int32),
         op_v=np.asarray(op_v, np.int32),
-        ok_col=ok_col, init_state=init_state, intern=intern.ids,
-        unintern=intern.values, crashed_ops=crashed)
+        init_state=init_state, intern=intern.ids,
+        unintern=intern.values, crashed_idx=crashed)
 
 
 def _window_scan(invoke_pos, return_pos, max_window: int) -> int:
@@ -524,7 +504,7 @@ def _assemble(pre: PrePacked, ret_slot, ret_op, active, slot_f,
         slot_v=slot_v[:, :W], slot_op=slot_op[:, :W],
         crashed=crashed_tbl[:, :W], init_state=pre.init_state,
         intern=pre.intern, unintern=pre.unintern,
-        crashed_ops=pre.crashed_ops)
+        crashed_idx=pre.crashed_idx)
     out._op_fv = (pre.op_f, pre.op_v, pre.invoke_pos)
     return out
 
@@ -546,7 +526,7 @@ def _assemble_dev(pre: PrePacked, lane) -> PackedHistory:
         slot_op=np.ascontiguousarray(slot_op[:R, :W], np.int32),
         crashed=np.ascontiguousarray(crashed[:R, :W]),
         init_state=pre.init_state, intern=pre.intern,
-        unintern=pre.unintern, crashed_ops=pre.crashed_ops)
+        unintern=pre.unintern, crashed_idx=pre.crashed_idx)
     out._op_fv = (pre.op_f, pre.op_v, pre.invoke_pos)
     out._reduction_tables = (
         np.ascontiguousarray(pure[:R, :W]),

@@ -28,10 +28,14 @@ reference checker and the TPU BFS kernel consume:
 
 from __future__ import annotations
 
+import operator
 import os
+import threading
 import time
-from dataclasses import dataclass
-from operator import attrgetter
+from collections.abc import Sequence
+from dataclasses import dataclass, field
+from itertools import repeat
+from operator import attrgetter, itemgetter
 from typing import Any, Callable
 
 import numpy as np
@@ -42,6 +46,7 @@ from jepsen_tpu.history import FAIL as H_FAIL
 from jepsen_tpu.models import kernels as K
 from jepsen_tpu.models.kernels import (F_IDS, NIL, VALUE_WIDTH, KernelModel,
                                        kernel_for)
+from jepsen_tpu.obs import trace as obs_trace
 
 
 def fast_pack_enabled() -> bool:
@@ -103,7 +108,7 @@ class PackedHistory:
 
     model: Any                   # the Python model (semantic reference)
     kernel: KernelModel | None   # device kernel, None if model unsupported
-    ops: list[LinOp]             # logical ops (reporting / witnesses)
+    ops: Sequence[LinOp]         # logical ops (reporting / witnesses)
     window: int                  # W = bitset width in use
     R: int                       # number of return events
     ret_slot: np.ndarray         # i32[R]   slot of the returning op
@@ -116,11 +121,17 @@ class PackedHistory:
     init_state: np.ndarray       # i32[S]
     intern: dict                 # value -> id
     unintern: list               # id -> value
-    crashed_ops: list[LinOp]     # info ops pending at end (never linearized)
+    crashed_idx: list            # indices into ops of the crashed ops
 
     @property
     def state_width(self) -> int:
         return len(self.init_state)
+
+    @property
+    def crashed_ops(self) -> list[LinOp]:
+        """The info ops pending at the end (never linearized), in
+        invocation order; built when read."""
+        return [self.ops[i] for i in self.crashed_idx]
 
 
 MAX_WINDOW = 64
@@ -179,42 +190,147 @@ def pair_ops(history: list[Op]) -> list[LinOp]:
     return ops
 
 
+@dataclass
+class OpColumns:
+    """The fast pairing's per-op columns, in invocation order: what each
+    :class:`LinOp` holds, as arrays over ``history`` (the events the
+    positions index). ``built`` caches the LinOps read so far, shared by
+    every :class:`LinOpSeq` over these columns; ``lock`` serializes the
+    builds of readers in different threads."""
+
+    history: list
+    invoke_pos: np.ndarray       # i64[n]
+    return_pos: np.ndarray       # i64[n]  (-1 = crashed)
+    ok: np.ndarray               # bool[n]
+    f_code: np.ndarray           # [n]  index into f_names
+    f_names: list                # distinct fs, first-occurrence order
+    value: np.ndarray            # object[n]  semantic value
+    built: dict = field(default_factory=dict)    # op id -> LinOp
+    lock: Any = field(default_factory=threading.Lock)
+
+
+class LinOpSeq(Sequence):
+    """Read-only sequence of LinOps over :class:`OpColumns`. An op is
+    built on first read and cached, so identity is stable and a verdict
+    that names a few ops builds only those;
+    ``trace.count("prepare.ops_built")`` counts the builds. Equal to a
+    list (or another sequence of this kind) holding equal LinOps in the
+    same order. A loop that reads every op many times should copy the
+    sequence to a list once."""
+
+    __slots__ = ("cols",)
+
+    def __init__(self, cols: OpColumns):
+        self.cols = cols
+
+    def __len__(self) -> int:
+        return len(self.cols.ok)
+
+    def __getitem__(self, k):
+        if isinstance(k, slice):
+            return self._get_many(range(len(self))[k])
+        n = len(self)
+        k = operator.index(k)
+        if k < 0:
+            k += n
+        if not 0 <= k < n:
+            raise IndexError("LinOpSeq index out of range")
+        built = self.cols.built
+        if k not in built:
+            self._build([k])
+        return built[k]
+
+    def __iter__(self):
+        return iter(self._get_many(range(len(self))))
+
+    def __eq__(self, other):
+        if not isinstance(other, (LinOpSeq, list)):
+            return NotImplemented
+        return len(self) == len(other) and \
+            all(map(operator.eq, self, other))
+
+    def __repr__(self) -> str:
+        return (f"LinOpSeq({len(self)} ops, "
+                f"{len(self.cols.built)} built)")
+
+    def _get_many(self, ids) -> list:
+        built = self.cols.built
+        missing = [i for i in ids if i not in built] if built else ids
+        if missing:
+            self._build(missing)
+        return list(map(built.__getitem__, ids))
+
+    def _build(self, ids) -> None:
+        """Build and cache the ops ``ids`` that no reader has built."""
+        c = self.cols
+        H = c.history
+        new = LinOp.__new__
+        with c.lock:
+            if c.built:
+                ids = [i for i in ids if i not in c.built]
+            ix = np.arange(ids.start, ids.stop, ids.step) \
+                if isinstance(ids, range) else np.array(ids, np.int64)
+            out = []
+            app = out.append
+            for ip, rp, ok, value in zip(
+                    c.invoke_pos[ix].tolist(), c.return_pos[ix].tolist(),
+                    c.ok[ix].tolist(), c.value[ix].tolist()):
+                inv = H[ip]
+                o = new(LinOp)
+                o.__dict__ = {
+                    "op_index": inv.index if inv.index is not None else ip,
+                    "process": inv.process, "f": inv.f, "value": value,
+                    "ok": ok, "invoke_pos": ip,
+                    "return_pos": rp if ok else None}
+                app(o)
+            c.built.update(zip(ids, out))
+        obs_trace.count("prepare.ops_built", len(out))
+
+
 _TYPE_CODE = {INVOKE: 0, OK: 1, H_FAIL: 2, INFO: 3}
+_get_type = attrgetter("type")
+_get_f = attrgetter("f")
+_get_process = attrgetter("process")
+_get_value = attrgetter("value")
 
 
-def _pair_ops_vec(history: list[Op]) -> list[LinOp]:
-    """Vectorized twin of :func:`pair_ops` (JEPSEN_TPU_FAST_PACK).
-    Produces the identical LinOp list (same order, same ops, same
-    errors) as the spec loop."""
-    return _pair_ops_vec_arrays(history)[0]
+def _factorize(xs: list):
+    """Dense ids of hashables, in first-occurrence order, in C-level
+    passes: ``(ids[len(xs)], {x: id})``. The ids take the smallest
+    unsigned dtype, which numpy's stable sort radix-sorts."""
+    d = dict.fromkeys(xs)
+    for i, k in enumerate(d):
+        d[k] = i
+    return (np.fromiter(map(d.__getitem__, xs),
+                        np.min_scalar_type(len(d)), len(xs)), d)
+
+
+def _empty_cols(history: list):
+    z = np.zeros(0, np.int64)
+    cols = OpColumns(history, z, z, np.zeros(0, bool), z, [],
+                     np.zeros(0, object))
+    return (LinOpSeq(cols), np.zeros(0, np.int32), np.zeros(0, np.int32),
+            cols.ok)
 
 
 def _pair_ops_vec_arrays(history: list[Op]):
-    """Core of :func:`_pair_ops_vec`: the per-event pending-dict walk
-    becomes a stable sort by (process, time) — within one process the
-    relevant events alternate invoke/completion, so a completion pairs
-    with its invocation exactly when the previous same-process event is
-    an invoke, and an invoke following an invoke is the double-invoke
-    error. Returns ``(ops, invoke_pos, return_pos, ok)`` with the
-    position/ok columns as arrays so :func:`prepare` skips re-walking
-    the LinOp list (return_pos is -1 for crashed ops)."""
-    empty = ([], np.zeros(0, np.int32), np.zeros(0, np.int32),
-             np.zeros(0, bool))
+    """Vectorized twin of :func:`pair_ops` (JEPSEN_TPU_FAST_PACK): the
+    per-event pending-dict walk becomes a stable sort by (process, time)
+    — within one process the relevant events alternate invoke/
+    completion, so a completion pairs with its invocation exactly when
+    the previous same-process event is an invoke, and an invoke
+    following an invoke is the double-invoke error. Returns ``(ops,
+    invoke_pos, return_pos, ok)``: ``ops`` a :class:`LinOpSeq` over the
+    per-op columns, equal to the spec's list (same order, same ops,
+    same errors) but building no LinOp until one is read; the
+    position/ok columns as arrays (return_pos is -1 for crashed ops)."""
     n_ev = len(history)
     if n_ev == 0:
-        return empty
-    get_code = _TYPE_CODE.get
-    tc = np.frombuffer(
-        bytes(get_code(t, 4) for t in map(attrgetter("type"), history)),
-        np.int8)
-    fs = list(map(attrgetter("f"), history))
-    # Factorize processes / fs to dense ids (arbitrary hashables).
-    pmap: dict = {}
-    pids = np.fromiter((pmap.setdefault(op.process, len(pmap))
-                        for op in history), np.int64, n_ev)
-    fmap: dict = {}
-    fids = np.fromiter((fmap.setdefault(f, len(fmap)) for f in fs),
-                       np.int64, n_ev)
+        return _empty_cols(history)
+    tc = np.fromiter(map(_TYPE_CODE.get, map(_get_type, history),
+                         repeat(4)), np.int8, n_ev)
+    pids, pmap = _factorize(list(map(_get_process, history)))
+    fids, fmap = _factorize(list(map(_get_f, history)))
     keep = np.ones(n_ev, bool)
     nem = pmap.get("nemesis")
     if nem is not None:
@@ -225,7 +341,7 @@ def _pair_ops_vec_arrays(history: list[Op]):
             keep &= fids != fe
     idx = np.flatnonzero(keep)
     if idx.size == 0:
-        return empty
+        return _empty_cols(history)
     # Group by process, time order within group (stable).
     g = idx[np.argsort(pids[idx], kind="stable")]
     pid_s = pids[g]
@@ -254,36 +370,38 @@ def _pair_ops_vec_arrays(history: list[Op]):
                                           g.dtype)])
     okc = np.concatenate([tc_s[pj] == 1,               # OK completions
                           np.zeros(int(dangling.sum()), bool)])
-    # Crashed reads constrain nothing: drop them here, vectorized, so
-    # the build loop below is branch-light and 1:1 with the arrays.
+    # Crashed reads constrain nothing: drop them.
     rf = fmap.get("read")
     if rf is not None:
         keep2 = okc | (fids[ipos] != rf)
         ipos, cpos, okc = ipos[keep2], cpos[keep2], okc[keep2]
     order = np.argsort(ipos, kind="stable")
     ipos, cpos, okc = ipos[order], cpos[order], okc[order]
-    ops: list[LinOp] = []
-    app = ops.append
-    H = history
-    new = LinOp.__new__
-    for ip, cp, ok in zip(ipos.tolist(), cpos.tolist(), okc.tolist()):
-        inv = H[ip]
-        f = inv.f
-        if f == "read":                  # ok is always True here
-            value = H[cp].value
-        elif f == "dequeue" and ok:
-            cv = H[cp].value
-            value = cv if cv is not None else inv.value
-        else:
-            value = inv.value
-        o = new(LinOp)
-        o.__dict__ = {
-            "op_index": inv.index if inv.index is not None else ip,
-            "process": inv.process, "f": f, "value": value, "ok": ok,
-            "invoke_pos": ip, "return_pos": cp if ok else None}
-        app(o)
-    return (ops, ipos.astype(np.int32, copy=False),
-            np.where(okc, cpos, -1).astype(np.int32, copy=False), okc)
+    f_code = fids[ipos]
+
+    def values_at(pos):
+        return np.fromiter(map(_get_value, map(history.__getitem__,
+                                               pos.tolist())),
+                           object, len(pos))
+
+    # _semantic_value's rules: the completion's value for reads (all ok
+    # here) and for ok dequeues that saw one, else the invocation's.
+    value = values_at(ipos)
+    if rf is not None:
+        rd = np.flatnonzero(f_code == rf)
+        value[rd] = values_at(cpos[rd])
+    df = fmap.get("dequeue")
+    if df is not None:
+        dq = np.flatnonzero((f_code == df) & okc)
+        cv = values_at(cpos[dq])
+        seen = np.fromiter(map(operator.is_not, cv, repeat(None)), bool,
+                           len(dq))
+        value[dq[seen]] = cv[seen]
+    return_pos = np.where(okc, cpos, -1)
+    cols = OpColumns(history, ipos, return_pos, okc, f_code, list(fmap),
+                     value)
+    return (LinOpSeq(cols), ipos.astype(np.int32),
+            return_pos.astype(np.int32), okc)
 
 
 class _Interner:
@@ -327,7 +445,7 @@ MAX_QUEUE_VALUES = 32     # distinct unordered-queue values (state width)
 MAX_FIFO_CAP = 31         # fifo depth bound (state width 32)
 
 
-def _max_queue_depth(ops: list[LinOp], n_initial: int) -> int:
+def _max_queue_depth(ops: Sequence[LinOp], n_initial: int) -> int:
     """Upper bound on FIFO depth over every possible linearization: at any
     event position t, at most the enqueues *invoked* by t have linearized,
     and at least the ok dequeues *returned* by t have linearized."""
@@ -350,7 +468,7 @@ def _no_kernel(n: int):
             np.full((n, VALUE_WIDTH), int(NIL), np.int32))
 
 
-def _kernelize(model, ops: list[LinOp], intern: _Interner):
+def _kernelize(model, ops: Sequence[LinOp], intern: _Interner):
     """Build the device kernel sized for this history plus the per-op
     interned (f, value-words) tables.
 
@@ -381,6 +499,7 @@ def _kernelize(model, ops: list[LinOp], intern: _Interner):
         return kernel, init_state, op_f, op_v
 
     if isinstance(model, model_ns.SetModel):
+        ops = list(ops)                  # read in several passes
         if any(o.f not in F_IDS for o in ops) or \
                 any(o.f == "add" and o.value is None for o in ops) or \
                 any(e is None for e in model.s):
@@ -426,6 +545,7 @@ def _kernelize(model, ops: list[LinOp], intern: _Interner):
         return kernel, kernel.init_state(), op_f, op_v
 
     if isinstance(model, (model_ns.UnorderedQueue, model_ns.FIFOQueue)):
+        ops = list(ops)                  # read in several passes
         initial = list(model.pending)
         if any(o.f not in F_IDS for o in ops) \
                 or any(v is None for v in initial) \
@@ -468,75 +588,59 @@ def _kernelize(model, ops: list[LinOp], intern: _Interner):
     return _no_kernel(n)
 
 
-def _kernelize_vec(model, ops: list[LinOp], intern: _Interner):
+def _kernelize_vec(model, cols: OpColumns, intern: _Interner):
     """Vectorized twin of :func:`_kernelize` for the fixed-layout band
-    (register / cas-register / mutex) over int-or-None values: the
-    first-occurrence interner becomes one ``np.unique`` + argsort pass.
-    Returns the spec-identical ``(kernel, init_state, op_f, op_v)`` or
-    None when the model/value domain defeats the vector form (caller
-    falls back to the spec loop, which handles everything)."""
+    (register / cas-register / mutex) over int-or-None values, read from
+    the fast pairing's f and value columns: the first-occurrence
+    interner becomes one ``np.unique`` + argsort pass. Returns the
+    spec-identical ``(kernel, init_state, op_f, op_v)`` or None when the
+    model/value domain defeats the vector form (caller falls back to the
+    spec loop, which handles everything)."""
     if not isinstance(model, (model_ns.CASRegister, model_ns.Register,
                               model_ns.Mutex)):
         return None
-    n = len(ops)
-    fs = [o.f for o in ops]
-    get = F_IDS.get
-    op_f = np.fromiter((get(f, -1) for f in fs), np.int64, n) \
-        if n else np.zeros(0, np.int64)
+    n = len(cols.f_code)
+    f_tbl = np.array([F_IDS.get(f, -1) for f in cols.f_names], np.int64)
+    op_f = f_tbl[cols.f_code] if n else np.zeros(0, np.int64)
     bad = np.flatnonzero(op_f < 0)
     if bad.size:
         raise UnsupportedHistory(
-            f"unknown op f={fs[int(bad[0])]!r} for device packing")
+            f"unknown op f={cols.f_names[cols.f_code[bad[0]]]!r} "
+            "for device packing")
     kernel = kernel_for(model)
     # The intern-call sequence, in the exact order the spec loop makes
-    # them: model.value first (registers), then per op — assembled by
-    # np.repeat over per-op entry counts (cas: 2 words, read/write: 1,
-    # else 0) with a small fix-up loop over the cas subset only.
-    f_cas = F_IDS.get("cas", -9)
-    f_read = F_IDS.get("read", -9)
-    f_write = F_IDS.get("write", -9)
-    is_cas = op_f == f_cas
-    ent = is_cas * 2 + ((op_f == f_read) | (op_f == f_write))
-    tgt_i_ops = np.repeat(np.arange(n, dtype=np.int64), ent)
-    m_ops = len(tgt_i_ops)
-    tgt_w_ops = np.zeros(m_ops, np.int64)
+    # them: model.value first (registers), then per op its entries (cas:
+    # both words of the pair, read/write: the value, else none).
+    is_cas = op_f == F_IDS.get("cas", -9)
+    rw = (op_f == F_IDS.get("read", -9)) | (op_f == F_IDS.get("write", -9))
+    ent = is_cas * 2 + rw
     starts = np.cumsum(ent) - ent        # seq start of each op's run
     ci = np.flatnonzero(is_cas)
-    tgt_w_ops[starts[ci] + 1] = 1        # second cas word
-    vlist = [o.value for o in ops]
-    arr = np.empty(n, object)
-    arr[:] = vlist
-    seq = np.repeat(arr, ent).tolist()   # cas slots hold the pair; fix:
-    for j, i in zip(starts[ci].tolist(), ci.tolist()):
-        v = vlist[i]
-        if not isinstance(v, (list, tuple)) or len(v) != 2:
-            raise UnsupportedHistory(
-                f"cas value must be a pair: {v!r}")
-        seq[j] = v[0]
-        seq[j + 1] = v[1]
-    if isinstance(model, model_ns.Mutex):
-        tgt_i = tgt_i_ops
-        tgt_w = tgt_w_ops
-    else:
-        seq.insert(0, model.value)
-        tgt_i = np.concatenate([np.full(1, -1, np.int64), tgt_i_ops])
-        tgt_w = np.concatenate([np.zeros(1, np.int64), tgt_w_ops])
-    m = len(seq)
-    flags = bytearray(m)                 # 1 where seq[j] is a live int
-    vals_list: list = []
-    vapp = vals_list.append
-    lo, hi = -(1 << 62), 1 << 62
-    for j, v in enumerate(seq):
-        if v is None:
-            continue
-        if type(v) is not int or v < lo or v >= hi:
-            return None                  # non-int domain: spec interner
-        flags[j] = 1
-        vapp(v)
-    ids_all = np.full(m, int(NIL), np.int64)
-    nn = np.frombuffer(bytes(flags), bool)
-    vals = np.array(vals_list, np.int64) \
-        if vals_list else np.zeros(0, np.int64)
+    ri = np.flatnonzero(rw)
+    cv = cols.value[ci]
+    if not set(map(type, cv)) <= {list, tuple} or \
+            not set(map(len, cv)) <= {2}:
+        for v in cv.tolist():
+            if not isinstance(v, (list, tuple)) or len(v) != 2:
+                raise UnsupportedHistory(
+                    f"cas value must be a pair: {v!r}")
+    off = 0 if isinstance(model, model_ns.Mutex) else 1
+    seq = np.empty(off + int(ent.sum()), object)      # None-filled
+    if off:
+        seq[0] = model.value
+    seq[off + starts[ri]] = cols.value[ri]
+    seq[off + starts[ci]] = np.fromiter(map(itemgetter(0), cv), object,
+                                        len(ci))
+    seq[off + starts[ci] + 1] = np.fromiter(map(itemgetter(1), cv),
+                                            object, len(ci))
+    if not set(map(type, seq)) <= {int, type(None)}:
+        return None                      # non-int domain: spec interner
+    nn = np.not_equal(seq, None)
+    try:
+        vals = seq[nn].astype(np.int64)
+    except OverflowError:
+        return None                      # past int64: spec interner
+    ids_all = np.full(len(seq), int(NIL), np.int64)
     if vals.size:
         uniq, first, inverse = np.unique(vals, return_index=True,
                                          return_inverse=True)
@@ -546,15 +650,15 @@ def _kernelize_vec(model, ops: list[LinOp], intern: _Interner):
         ids_all[nn] = idmap[inverse]
         intern.values = uniq[rank].tolist()
         intern.ids = {v: i for i, v in enumerate(intern.values)}
+    ids = ids_all[off:].astype(np.int32)
     op_v = np.full((n, kernel.value_width), int(NIL), np.int32)
-    ti = np.asarray(tgt_i, np.int64)
-    tw = np.asarray(tgt_w, np.int64)
-    opm = ti >= 0
-    op_v[ti[opm], tw[opm]] = ids_all[opm].astype(np.int32)
-    if isinstance(model, model_ns.Mutex):
-        init_state = kernel.init_state()
-    else:
+    op_v[ri, 0] = ids[starts[ri]]
+    op_v[ci, 0] = ids[starts[ci]]
+    op_v[ci, 1] = ids[starts[ci] + 1]
+    if off:
         init_state = np.array([int(ids_all[0])], np.int32)
+    else:
+        init_state = kernel.init_state()
     return kernel, init_state, op_f.astype(np.int32), op_v
 
 
@@ -740,6 +844,30 @@ def _pack_events_py(invoke_pos, return_pos, op_f, op_v, max_window,
     return ret_slot, ret_op, active, slot_f, slot_v, slot_op, max_used
 
 
+def _pair(history: list[Op], fast: bool):
+    """``(ops, invoke_pos, return_pos, ok)`` of :func:`prepare` and
+    ``pack_dev.prepack``: the fast pairing's lazy sequence, or the spec
+    walk's list with its columns. Counts the LinOps built."""
+    if fast:
+        obs_trace.count("prepare.ops_built", 0)
+        return _pair_ops_vec_arrays(history)
+    ops = pair_ops(history)
+    obs_trace.count("prepare.ops_built", len(ops))
+    n = len(ops)
+    return (ops, np.fromiter((o.invoke_pos for o in ops), np.int32, n),
+            np.fromiter((-1 if o.return_pos is None else o.return_pos
+                         for o in ops), np.int32, n),
+            np.fromiter((o.ok for o in ops), bool, n))
+
+
+def _kernelize_ops(model, ops, intern: _Interner):
+    """:func:`_kernelize_vec` over a :class:`LinOpSeq`'s columns where
+    it applies, else the spec :func:`_kernelize`."""
+    kv = _kernelize_vec(model, ops.cols, intern) \
+        if isinstance(ops, LinOpSeq) else None
+    return kv if kv is not None else _kernelize(model, ops, intern)
+
+
 def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
     """Pack a history for the frontier search. See module docstring.
 
@@ -748,42 +876,22 @@ def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
     BIT-IDENTICAL to the Python spec walk (fuzzed in
     tests/test_fast_pack.py); ``=0`` — or data the vector form does not
     cover — takes the spec path below unchanged."""
-    from jepsen_tpu.obs import trace as obs_trace
-
     t_start = time.perf_counter()
     fast = fast_pack_enabled()
     # Opened before list(history): it covers all of the host pack.
     with obs_trace.span("prepare", mode="vec" if fast else "spec") as sp:
-        ok_col = None
         with obs_trace.span("prepare.pair"):
             history = list(history)
-            if fast:
-                ops, invoke_pos, return_pos, ok_col = \
-                    _pair_ops_vec_arrays(history)
-            else:
-                ops = pair_ops(history)
+            ops, invoke_pos, return_pos, ok = _pair(history, fast)
         intern = _Interner()
 
         # Per-op (f, values) interned ONCE up front — the packing walk
         # below references ops (R x W) times, never re-interning.
         with obs_trace.span("prepare.kernelize"):
-            kv = _kernelize_vec(model, ops, intern) if fast else None
-            if kv is None:
-                kernel, init_state, op_f, op_v = _kernelize(
-                    model, ops, intern)
-            else:
-                kernel, init_state, op_f, op_v = kv
-
+            kernel, init_state, op_f, op_v = _kernelize_ops(
+                model, ops, intern)
         n = len(ops)
-        if ok_col is not None:
-            R = int(ok_col.sum())
-        else:
-            R = sum(1 for o in ops if o.ok)
-            invoke_pos = np.fromiter(
-                (o.invoke_pos for o in ops), np.int32, n)
-            return_pos = np.fromiter(
-                (-1 if o.return_pos is None else o.return_pos
-                 for o in ops), np.int32, n)
+        R = int(ok.sum())
 
         fill_fv = kernel is not None
         packed = None
@@ -808,11 +916,7 @@ def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
             packed
 
         with obs_trace.span("prepare.tables"):
-            if ok_col is not None:
-                crashed = [ops[i]
-                           for i in np.flatnonzero(~ok_col).tolist()]
-            else:
-                crashed = [o for o in ops if o.return_pos is None]
+            crashed = np.flatnonzero(~ok).tolist()
 
             # Per-slot crashed mask. CONSUMED BY THE DEVICE ENGINES: the
             # crashed-op canonical chains (reduction_tables) and the
@@ -837,7 +941,7 @@ def prepare(model, history, max_window: int = MAX_WINDOW) -> PackedHistory:
                 slot_v=slot_v[:, :W], slot_op=slot_op[:, :W],
                 crashed=crashed_tbl[:, :W],
                 init_state=init_state, intern=intern.ids,
-                unintern=intern.values, crashed_ops=crashed)
+                unintern=intern.values, crashed_idx=crashed)
             # Per-op interned tables ride along for the vectorized
             # chain core (reduction_tables); views rebuilt elsewhere
             # (service codec, stream packer) recover them from the slot

@@ -695,7 +695,8 @@ class IncrementalPacker:
             slot_op=t["slot_op"][:, :W], crashed=t["crashed"][:, :W],
             init_state=self.init_state, intern=self.intern.ids,
             unintern=self.intern.values,
-            crashed_ops=[o for o in self.ops if o.return_pos is None])
+            crashed_idx=[i for i, o in enumerate(self.ops)
+                         if o.return_pos is None])
         # Inject the position-keyed reduction cache: recomputing via
         # prepare.reduction_tables here would misclassify a resolved-
         # but-later-returning op as crashed (its return row is not yet
