@@ -315,11 +315,13 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
                     else:
                         snapshots[:] = [(base, F)]
                 if use_pallas:
-                    # Bucket the kernel grid to the chunk's actual row
-                    # count so a short final chunk doesn't pay for
-                    # thousands of no-op steps (and don't upload the
-                    # unused table tail at all).
-                    n_pad = min(chunk, max(512, 1 << (n - 1).bit_length()))
+                    # Two grid sizes a width: the chunk, and an eighth
+                    # of it for a chunk of no more rows (short histories
+                    # keep small tables). Each size is one more kernel to
+                    # trace, lower and compile in set-up, seconds on the
+                    # chip; a padded block past n only fetches its
+                    # tables.
+                    n_pad = chunk if 8 * n > chunk else -(-chunk // 8)
                     sl = lambda a: _chunk_slice(  # noqa: E731
                         a, base, chunk)[:n_pad]
                     masks = dp.transition_masks(
@@ -330,6 +332,7 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
                     F, r_done, dead = dp.pallas_chunk(
                         F, jnp.int32(n), masks, jnp.asarray(sl(ret_slot_h)),
                         w=w_cur, ns=ns, chunk=n_pad, interpret=interpret)
+                    rb = dp.block_rows(n_pad)
                     # The pallas closure runs to true fixpoint (its
                     # waived unbounded loop) — no truncation channel to
                     # consult.
@@ -368,6 +371,8 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
         if dead_b:
             r_done = int(r_done)
             obs_trace.count("dense.rows", r_done)
+            if use_pallas:
+                obs_trace.count("dense.grid_steps", -(-r_done // rb))
             r = base + r_done - 1
             ret = p.ops[int(p.ret_op[r])]
             out = {"valid?": False, "analyzer": "tpu-dense",
@@ -385,6 +390,10 @@ def check_packed(p: PackedHistory, chunk: int = CHUNK, cancel=None,
                                                cancel=cancel))
             return out
         obs_trace.count("dense.rows", n)
+        if use_pallas:
+            # Grid steps that swept rows; the padding steps past n,
+            # which fetch their block and skip its rows, are left out.
+            obs_trace.count("dense.grid_steps", -(-n // rb))
         base += n
 
     with obs_trace.span("dense.wait"):

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from jepsen_tpu.lin import dense_pallas, psort, psort_fused
+from jepsen_tpu.lin.dense import MAX_DENSE_STATES
 
 # The headline 100k-op history's window (dense plan width 13).
 HEADLINE_W = 13
@@ -72,7 +73,7 @@ def _dense(chip, w, ns):
     chunk = 512
     return _compile(("dense", w, ns), lambda: dense_pallas.pallas_chunk.lower(
         _shape(chip, (1 << w,)), _shape(chip, (), jnp.int32),
-        _shape(chip, (chunk, w, ns)), _shape(chip, (chunk,), jnp.int32),
+        _shape(chip, (w, ns, chunk)), _shape(chip, (chunk,), jnp.int32),
         w=w, ns=ns, chunk=chunk))
 
 
@@ -110,11 +111,21 @@ def _psort(chip, name):
     return _compile((name,), lambda: _PSORT[name](chip))
 
 
-@pytest.mark.parametrize("w,ns", [(HEADLINE_W, 8),
-                                  (dense_pallas.MAX_PALLAS_W, 4)])
-def test_dense_pallas_chunk_compiles(chip, w, ns):
+@pytest.mark.parametrize("w,ns,row_block", [
+    (HEADLINE_W, 8, None), (dense_pallas.MAX_PALLAS_W, 4, None),
+    (10, MAX_DENSE_STATES, 2 * dense_pallas.ROW_BLOCK)],
+    ids=["13-8", "18-4", "10-32"])
+def test_dense_pallas_chunk_compiles(chip, monkeypatch, w, ns, row_block):
     # MAX_PALLAS_W at the smallest state bucket: the kernel's compile
     # time grows with w * ns, and the width is what the bound is about.
+    # (10, 32) at twice ROW_BLOCK rows a step holds more words of masks
+    # in SMEM than the largest block any width takes (MAX_PALLAS_W x 32
+    # states x ROW_BLOCK), and compiles in seconds: an overrun fails.
+    if row_block:
+        assert w * ns * row_block > \
+            dense_pallas.MAX_PALLAS_W * MAX_DENSE_STATES * \
+            dense_pallas.ROW_BLOCK
+        monkeypatch.setattr(dense_pallas, "ROW_BLOCK", row_block)
     _dense(chip, w, ns)
 
 

@@ -11,7 +11,8 @@ import pytest
 
 from jepsen_tpu import models as m
 from jepsen_tpu.history import History, info_op, invoke_op, ok_op
-from jepsen_tpu.lin import cpu, dense, prepare, synth
+from jepsen_tpu.lin import cpu, dense, dense_pallas, prepare, synth
+from jepsen_tpu.obs import trace as obs_trace
 
 
 def both(model, history, chunk=dense.CHUNK):
@@ -169,26 +170,84 @@ def test_pallas_backend_parity(seed):
     assert r["valid?"] == want, f"pallas={r} cpu={want}"
 
 
-def test_pallas_chunk_boundary_and_mutex():
-    h = synth.generate_mutex_history(60, concurrency=4, seed=3,
-                                     crash_prob=0.1)
-    p = prepare.prepare(m.mutex(), h)
+def _pallas_matches_xla(p, chunk=dense.CHUNK):
+    # Verdict, dead row and named op equal the XLA backend's.
+    rx = dense.check_packed(p, backend="xla", chunk=chunk)
+    rp = dense.check_packed(p, backend="pallas", chunk=chunk)
+    assert rp["valid?"] is rx["valid?"]
+    assert rp.get("dead-row") == rx.get("dead-row")
+    assert rp.get("op") == rx.get("op")
+    return rp
+
+
+def _register_400():
+    h = synth.generate_register_history(400, concurrency=4, seed=3,
+                                        crash_prob=0.02)
+    return prepare.prepare(m.cas_register(), h)
+
+
+@pytest.mark.parametrize("model,n_ops,crash_prob,chunk", [
+    ("mutex", 60, 0.1, 16),              # 16-row chunks and blocks
+    ("mutex", 300, 0.02, dense.CHUNK),   # one chunk under one block
+    ("cas_register", 400, 0.02, 200),    # 200-row chunks: padded blocks
+])
+def test_pallas_chunk_boundary_and_mutex(model, n_ops, crash_prob, chunk):
+    if model == "mutex":
+        h = synth.generate_mutex_history(n_ops, concurrency=4, seed=3,
+                                         crash_prob=crash_prob)
+        p = prepare.prepare(m.mutex(), h)
+    else:
+        p = _register_400()
+    assert p.R % chunk % dense_pallas.block_rows(chunk) != 0
+    assert dense.plan(p)[0] <= 10
     want = cpu.check_packed(p)["valid?"]
-    assert dense.check_packed(p, backend="pallas",
-                              chunk=16)["valid?"] == want
+    assert _pallas_matches_xla(p, chunk)["valid?"] == want
 
 
-def test_pallas_dead_row_matches_xla():
-    h = synth.corrupt_history(
-        synth.generate_register_history(120, concurrency=4, seed=7,
-                                        crash_prob=0.1), seed=7)
-    p = prepare.prepare(m.cas_register(), h)
-    rx = dense.check_packed(p, backend="xla")
-    rp = dense.check_packed(p, backend="pallas")
-    if rx["valid?"] is False:
-        assert rp["valid?"] is False
-        assert rp["dead-row"] == rx["dead-row"]
-        assert rp["op"] == rx["op"]
+def _dead_at(row):
+    """A 300-op register history whose frontier dies at return row
+    ``row`` (negative: from the end): the first seed's whose row ``row``
+    returns an ok read, with that read's value changed to one no op
+    writes. Returns the pack and the row."""
+    for seed in range(64):
+        h = synth.generate_register_history(300, concurrency=4, seed=seed,
+                                            value_range=3, crash_prob=0.05,
+                                            max_crashes=4)
+        p = prepare.prepare(m.cas_register(), h)
+        r = row % p.R
+        o = p.ops[int(p.ret_op[r])]
+        if o.f == "read":
+            ev = list(h)
+            ev[o.return_pos] = ev[o.return_pos].replace(
+                value=(o.value or 0) + 1000)
+            return prepare.prepare(m.cas_register(), History(ev)), r
+    raise AssertionError(f"no seed returns a read at row {row}")
+
+
+_RB = dense_pallas.ROW_BLOCK
+
+
+@pytest.mark.parametrize("row", [0, _RB - 1, _RB, _RB + _RB // 2, -1],
+                         ids=["first", "block-end", "block-start",
+                              "mid-block", "last"])
+def test_pallas_dead_row_matches_xla(row):
+    # The frontier dies at the first and last row of a block, mid-block
+    # and at the history's last row: the blocked sweep stops there.
+    p, r = _dead_at(row)
+    rp = _pallas_matches_xla(p)
+    assert rp["valid?"] is False and rp["dead-row"] == r
+
+
+def test_pallas_grid_steps_count_rows_per_block():
+    # A valid verdict counts the grid steps that swept rows: each swept
+    # ROW_BLOCK rows but the last, which swept the remainder.
+    p = _register_400()
+    with obs_trace.request() as req:
+        r = dense.check_packed(p, backend="pallas")
+    counts = req.record()["counts"]
+    steps, rows = counts["dense.grid_steps"], counts["dense.rows"]
+    assert r["valid?"] is True and rows == p.R
+    assert (steps - 1) * _RB < rows <= steps * _RB
 
 
 @pytest.mark.parametrize("seed", range(4))
